@@ -191,6 +191,12 @@ def host_q5_latency(rate: float = 20_000, duration_s: float = 4.0,
     while job.status != JOB_COMPLETED and time.monotonic() < deadline:
         cluster.step()
     wall = time.monotonic() - t_start
+    if job.status != JOB_COMPLETED:
+        # percentiles of a job cut at its deadline describe a backlog,
+        # not the paced run
+        raise RuntimeError(
+            f"q5 {placement} at {rate} ev/s did not complete within "
+            f"{deadline - t_start:.0f} s (status {job.status})")
     stats = job.execution.stats()
     engine = {k: stats[k] for k in ("items_in", "items_out", "calls",
                                     "idle_calls")}
@@ -308,6 +314,10 @@ def mp_q5_latency(rate: float = 20_000, duration_s: float = 4.0,
         t0 = cluster.backend.source_start(job.execution)
     finally:
         cluster.shutdown()
+    if job.status != JOB_COMPLETED:
+        raise RuntimeError(
+            f"mp q5 at {rate} ev/s did not complete within "
+            f"{deadline - t_start:.0f} s (status {job.status})")
     hist = LatencyHistogram()
     if t0 is not None:
         cut = t0 + warmup_s
@@ -452,7 +462,10 @@ def run(quick: bool = True, disorder_ms: int = 100,
     }
     # multiprocess substrate, always measured so the trajectory tracks it:
     # paced percentiles at the default worker count plus the saturation
-    # curve across 1/2/4 worker processes
+    # curve across 1/2/4 worker processes.  These fork their workers, so
+    # they run BEFORE any section below opens the accelerator: a chip
+    # belongs to one process, and a child forked after the parent opened
+    # it would fail or hang
     if backend != "mp":
         result["host_mp"] = mp_q5_latency(rate=host_rate,
                                           duration_s=duration,

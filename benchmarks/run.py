@@ -11,6 +11,7 @@ import argparse
 import json
 import pathlib
 import sys
+import traceback
 
 
 def _csv_rows(rows, key_metric="p99.99", scale=1000.0):
@@ -55,6 +56,8 @@ def main() -> None:
     args = ap.parse_args()
     quick = not args.full
 
+    from repro.jax_cache import enable_compile_cache
+    enable_compile_cache()
     from . import bench_device_tier, bench_figures, bench_latency, roofline
 
     all_rows = []
@@ -92,11 +95,15 @@ def main() -> None:
             ("kernels", lambda: bench_device_tier.bench_kernels(quick=quick)),
         ]
 
+    failed = []
     for name, fn in sections:
         try:
             rows = fn()
         except Exception as e:  # pragma: no cover
+            # report and go on with the other sections, but the run fails
+            traceback.print_exc()
             print(f"{name},0.0,ERROR={e!r}", flush=True)
+            failed.append(name)
             continue
         all_rows.extend(rows)
         for line in _csv_rows(rows):
@@ -123,6 +130,8 @@ def main() -> None:
     if traj.exists():
         n = len(json.loads(traj.read_text()))
         print(f"# perf trajectory: {traj} ({n} records)", file=sys.stderr)
+    if failed:
+        sys.exit(f"benchmark sections failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

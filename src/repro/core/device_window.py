@@ -537,3 +537,8 @@ class DeviceWindowProcessor(Processor):
     @property
     def bucket_collisions(self) -> int:
         return self._bucket_collisions
+
+    @property
+    def steps(self) -> int:
+        """Device steps this instance has dispatched."""
+        return self._steps

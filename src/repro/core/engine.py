@@ -36,8 +36,8 @@ from .dag import DAG, Edge, PARTITION_COUNT, Routing, Vertex
 from .events import MAX_TIME
 from .processor import ProcessorContext
 from .tasklet import (CooperativeWorker, EdgeCollector, InQueue,
-                      GUARANTEE_EXACTLY_ONCE, GUARANTEE_NONE,
-                      ProcessorTasklet, SnapshotContext)
+                      GUARANTEE_AT_LEAST_ONCE, GUARANTEE_EXACTLY_ONCE,
+                      GUARANTEE_NONE, ProcessorTasklet, SnapshotContext)
 
 JOB_RUNNING = "running"
 JOB_COMPLETED = "completed"
@@ -102,6 +102,13 @@ class JobConfig:
                  snapshot_interval_s: float = 1.0,
                  restart_policy: Optional[RestartPolicy] = None,
                  barrier_timeout_s: float = 5.0):
+        # an unknown name would run without barrier alignment: a typo
+        # must not silently weaken exactly-once to at-least-once
+        if processing_guarantee not in (GUARANTEE_NONE,
+                                        GUARANTEE_AT_LEAST_ONCE,
+                                        GUARANTEE_EXACTLY_ONCE):
+            raise ValueError(
+                f"unknown processing_guarantee {processing_guarantee!r}")
         self.name = name
         self.processing_guarantee = processing_guarantee
         self.snapshot_interval_s = snapshot_interval_s

@@ -1,3 +1,17 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas TPU kernels (``window_agg``, ``route``, ``decode_attn``), their
+jitted wrappers (``ops``) and pure-jnp oracles (``ref``)."""
+
+from __future__ import annotations
+
+from typing import Optional
+
+import jax
+
+
+def resolve_interpret(interpret: Optional[bool]) -> bool:
+    """A kernel's ``interpret=None`` default: compile it for the TPU when
+    JAX runs on one, interpret it anywhere else (the CPU test backend).
+    Passing a bool overrides the platform."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return interpret

@@ -16,11 +16,14 @@ is a contiguous (CS, dh) VMEM tile; positions > pos are masked.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from . import resolve_interpret
 
 NEG_INF = -1e30
 CS = 512          # kv chunk
@@ -63,7 +66,7 @@ def _kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
 
 
 def decode_attention(q, k, v, pos, *, chunk: int = CS,
-                     interpret: bool = True):
+                     interpret: Optional[bool] = None):
     """GQA flash-decode.
 
     q: (B, H, dh) with H = Hk * G;  k/v: (B, Hk, S, dh);  pos: scalar int32
@@ -94,6 +97,6 @@ def decode_attention(q, k, v, pos, *, chunk: int = CS,
             pltpu.VMEM((G, 1), jnp.float32),      # running denom
             pltpu.VMEM((G, dh), jnp.float32),     # running out
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(pos_arr, qg, k, v)
     return out.reshape(B, H, dh)

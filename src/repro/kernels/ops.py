@@ -1,16 +1,17 @@
 """jit'd public wrappers over the Pallas kernels.
 
-``interpret`` defaults to True on CPU (this container) and should be set
-False on real TPU hardware; the flag is threaded through so the same call
-sites serve both.
+``interpret=None`` (the default) follows the platform: the kernels compile
+for the TPU when JAX runs on one and run in the Pallas interpreter
+anywhere else (see :func:`repro.kernels.resolve_interpret`).  A bool
+forces either mode.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
-import jax.numpy as jnp
 
 from .decode_attn import decode_attention as _decode_attention
 from .route import route_counts as _route_counts, route_offsets
@@ -21,16 +22,17 @@ from .window_agg import window_agg as _window_agg
                    static_argnames=("n_key_buckets", "ring_len",
                                     "interpret"))
 def window_agg(keys, slots, values, valid, n_key_buckets: int,
-               ring_len: int, interpret: bool = True):
+               ring_len: int, interpret: Optional[bool] = None):
     return _window_agg(keys, slots, values, valid, n_key_buckets, ring_len,
                        interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("n_partitions", "interpret"))
-def route_counts(pids, valid, n_partitions: int, interpret: bool = True):
+def route_counts(pids, valid, n_partitions: int,
+                 interpret: Optional[bool] = None):
     return _route_counts(pids, valid, n_partitions, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def decode_attention(q, k, v, pos, interpret: bool = True):
+def decode_attention(q, k, v, pos, interpret: Optional[bool] = None):
     return _decode_attention(q, k, v, pos, interpret=interpret)

@@ -14,7 +14,8 @@ def window_agg_ref(keys, slots, values, valid, n_key_buckets: int,
                               dtype=jnp.float32)
     onehot_r = jax.nn.one_hot(jnp.where(valid, slots, -1), ring_len,
                               dtype=jnp.float32)
-    return jnp.einsum("nk,nr->kr", onehot_k, onehot_r * vals[:, None])
+    return jnp.einsum("nk,nr->kr", onehot_k, onehot_r * vals[:, None],
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 def route_counts_ref(pids, valid, n_partitions: int):

@@ -14,10 +14,13 @@ so each partition tile accumulates across event tiles.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from . import resolve_interpret
 
 BP = 128
 BN = 2048
@@ -36,12 +39,12 @@ def _kernel(pid_ref, out_ref, *, BP: int):
     iota = jax.lax.broadcasted_iota(jnp.int32, (pids.shape[0], BP), 1)
     onehot = jnp.where(pids[:, None] == base + iota, 1.0, 0.0
                        ).astype(jnp.float32)                  # (BN, BP)
-    out_ref[...] += jnp.sum(onehot, axis=0).astype(jnp.int32)
+    out_ref[...] += jnp.sum(onehot, axis=0, keepdims=True).astype(jnp.int32)
 
 
 def route_counts(pids, valid, n_partitions: int,
                  block_p: int = BP, block_n: int = BN,
-                 interpret: bool = True):
+                 interpret: Optional[bool] = None):
     """pids: (N,) int32 partition ids. Returns (P,) int32 counts."""
     N = pids.shape[0]
     P = n_partitions
@@ -49,14 +52,17 @@ def route_counts(pids, valid, n_partitions: int,
     bp = min(block_p, P)
     assert N % bn == 0 and P % bp == 0
     pids = jnp.where(valid, pids, -1).astype(jnp.int32)   # -1 matches nothing
-    return pl.pallas_call(
+    # the counts travel as one (1, P) row: a lane-aligned 2-D block, where
+    # a 1-D (bp,) block of a longer vector mismatches XLA's tiling of it
+    counts = pl.pallas_call(
         functools.partial(_kernel, BP=bp),
         grid=(P // bp, N // bn),
         in_specs=[pl.BlockSpec((bn,), lambda pt, nt: (nt,))],
-        out_specs=pl.BlockSpec((bp,), lambda pt, nt: (pt,)),
-        out_shape=jax.ShapeDtypeStruct((P,), jnp.int32),
-        interpret=interpret,
+        out_specs=pl.BlockSpec((1, bp), lambda pt, nt: (0, pt)),
+        out_shape=jax.ShapeDtypeStruct((1, P), jnp.int32),
+        interpret=resolve_interpret(interpret),
     )(pids)
+    return counts[0]
 
 
 def route_offsets(pids, valid, n_partitions: int, **kw):

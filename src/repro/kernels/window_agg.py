@@ -18,10 +18,13 @@ matmul dim.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from . import resolve_interpret
 
 BK = 128     # key-bucket tile (MXU-aligned)
 BN = 1024    # event tile
@@ -46,14 +49,17 @@ def _kernel(key_ref, slot_ref, val_ref, out_ref, *, R: int, BK: int):
     r_iota = jax.lax.broadcasted_iota(jnp.int32, (keys.shape[0], R), 1)
     onehot_rv = jnp.where(slots[:, None] == r_iota, 1.0, 0.0
                           ).astype(jnp.float32) * vals[:, None]  # (BN, R)
+    # HIGHEST: at the default precision the MXU takes f32 operands as
+    # bfloat16, which rounds any event value above 256
     out_ref[...] += jax.lax.dot_general(
         onehot_k, onehot_rv, (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)                    # (BK, R)
 
 
 def window_agg(keys, slots, values, valid, n_key_buckets: int, ring_len: int,
                block_k: int = BK, block_n: int = BN,
-               interpret: bool = True):
+               interpret: Optional[bool] = None):
     """keys/slots: (N,) int32; values/valid: (N,). Returns (K, R) f32.
 
     Non-tile-multiple shapes are handled by padding: the event axis pads
@@ -93,6 +99,6 @@ def window_agg(keys, slots, values, valid, n_key_buckets: int, ring_len: int,
         ],
         out_specs=pl.BlockSpec((bk, R), lambda kt, nt: (kt, 0)),
         out_shape=jax.ShapeDtypeStruct((K_padded, R), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(keys, slots, vals)
     return out[:K] if k_pad else out

@@ -25,7 +25,6 @@ import traceback
 
 import jax
 
-from ..compat import cost_analysis_dict
 from ..configs import SHAPES, applicable_cells
 from .mesh import make_production_mesh
 from .specs import build_cell, lower_cell
@@ -120,7 +119,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool,
     finally:
         constraints.set_mesh(None)
     mem = compiled.memory_analysis()
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis() or {}
     hlo = compiled.as_text()
     coll = collective_bytes(hlo)
     result = {

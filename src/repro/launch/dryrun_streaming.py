@@ -17,7 +17,6 @@ import time
 import jax
 import jax.numpy as jnp
 
-from ..compat import cost_analysis_dict
 from ..streaming import StreamExecutor, StreamJobConfig, VectorWindowSpec
 from .dryrun import OUT_DIR, collective_bytes
 from .mesh import make_production_mesh
@@ -58,7 +57,7 @@ def main():
         snap_lowered = jax.jit(ex._build_snapshot()).lower(state_s)
         snap_compiled = snap_lowered.compile()
     mem = compiled.memory_analysis()
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis() or {}
     coll = collective_bytes(compiled.as_text())
     snap_coll = collective_bytes(snap_compiled.as_text())
     result = {

@@ -25,7 +25,11 @@ def make_production_mesh(*, multi_pod: bool = False):
             "XLA_FLAGS=--xla_force_host_platform_device_count=512 before "
             "importing jax")
     if len(devices) == n:
-        return jax.make_mesh(shape, axes)
+        # Auto axes: the sharding rules place data with
+        # with_sharding_constraint, which Explicit axes (make_mesh's
+        # default since JAX 0.7) refuse
+        return jax.make_mesh(shape, axes, axis_types=(
+            jax.sharding.AxisType.Auto,) * len(axes))
     dev_array = np.asarray(devices[:n]).reshape(shape)
     return jax.sharding.Mesh(dev_array, axes)
 

@@ -70,10 +70,14 @@ class NexmarkGenerator:
     """Callable ``gen(seq) -> (ts_ms, key, value)`` for the paced source."""
 
     def __init__(self, rate: float, n_keys: int = 10_000,
-                 auction_filter_mod: int = 123):
+                 auction_filter_mod: int = 123, seed: int = 0):
         self.rate = rate
         self.n_keys = n_keys
         self.auction_filter_mod = auction_filter_mod
+        #: ``seed`` draws a different stream of the same shape (seed 0 is
+        #: the historical stream): it offsets the splitmix64 input
+        self.seed = seed
+        self._offset = (seed * 0xBF58476D1CE4E5B9) & 0xFFFFFFFFFFFFFFFF
 
     def timestamp_ms(self, seq: int) -> int:
         return int(seq * 1000 / self.rate)
@@ -81,7 +85,7 @@ class NexmarkGenerator:
     def __call__(self, seq: int) -> Tuple[int, Any, Any]:
         ts = int(seq * 1000 / self.rate)
         # splitmix64 inlined: this is called once per generated event
-        x = (seq + 0x9E3779B97F4A7C15) & 0xFFFFFFFFFFFFFFFF
+        x = (seq + self._offset + 0x9E3779B97F4A7C15) & 0xFFFFFFFFFFFFFFFF
         x = ((x ^ (x >> 30)) * 0xBF58476D1CE4E5B9) & 0xFFFFFFFFFFFFFFFF
         x = ((x ^ (x >> 27)) * 0x94D049BB133111EB) & 0xFFFFFFFFFFFFFFFF
         r = x ^ (x >> 31)
@@ -118,7 +122,7 @@ class NexmarkGenerator:
         # ts = int(seq * 1000 / rate): seq*1000 is float64-exact for any
         # realistic run length, so the double rounding matches Python's
         ts = (seqs.astype(np.float64) * 1000.0 / self.rate).astype(np.int64)
-        r = _mix64_vec(seqs.astype(_U64))
+        r = _mix64_vec(seqs.astype(_U64) + _U64(self._offset))
         slot = seqs % TOTAL_PROPORTION
         kind = np.where(
             slot >= PERSON_PROPORTION + AUCTION_PROPORTION, KIND_BID,

@@ -229,7 +229,11 @@ def emit(spec: VectorWindowSpec, state: Dict
                   & (ring_f[None, :] <= L[:, None])
                   & (ring_f[None, :] >= 0) & ready[:, None])
         masks = jnp.where(in_win, 1.0, 0.0).astype(panes.dtype)  # (E, R)
-        results = masks @ panes                                  # (E, K)
+        # HIGHEST: at DEFAULT precision the TPU multiplies f32 operands in
+        # bfloat16, which rounds a window sum above 256 (a summing Q5's
+        # price totals) — the results must equal the host's exactly
+        results = jnp.matmul(masks, panes,
+                             precision=jax.lax.Precision.HIGHEST)  # (E, K)
         # evict every frame retired by an emitted window (single pass)
         evict = jnp.any((ring_f[None, :] == (L - F + 1)[:, None])
                         & ready[:, None], axis=0) & (ring_f >= 0)

@@ -81,8 +81,8 @@ def test_coalescer_min_rule_and_done_exclusion():
 @pytest.fixture(scope="module")
 def mesh():
     # rule logic only reads mesh.shape / axis_names; build an abstract mesh
-    from repro.compat import abstract_mesh
-    return abstract_mesh((16, 16), ("data", "model"))
+    import jax
+    return jax.sharding.AbstractMesh((16, 16), ("data", "model"))
 
 
 def test_param_rules_train_vs_serve(mesh):

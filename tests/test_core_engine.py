@@ -148,3 +148,11 @@ def test_hash_join_stream_with_batch_side():
     for ev in out:
         probe, match = ev.value
         assert match[0] == probe
+
+
+@pytest.mark.parametrize("name", ["exactly-once", "EXACTLY_ONCE", "atleastonce"])
+def test_job_config_rejects_an_unknown_guarantee(name):
+    """A misspelt guarantee used to run without barrier alignment, i.e.
+    silently at-least-once."""
+    with pytest.raises(ValueError, match="processing_guarantee"):
+        JobConfig(processing_guarantee=name)

@@ -217,6 +217,23 @@ def test_nexmark_gen_block_matches_scalar():
         assert blk.value[i] == gen(i)[2].price
 
 
+def test_nexmark_seed_draws_another_stream_of_the_same_shape():
+    base = NexmarkGenerator(rate=7000, n_keys=40)
+    gen = NexmarkGenerator(rate=7000, n_keys=40, seed=7)
+    seqs = np.arange(300, dtype=np.int64)
+    blk = gen.gen_block(seqs)
+    for i in range(300):
+        ts, key, val = gen(i)
+        assert int(blk.ts[i]) == ts and int(blk.key[i]) == key
+        assert repr(blk.value_at(i)) == repr(val)
+        assert ts == base(i)[0]
+        assert type(val) is type(base(i)[2])
+    assert blk.key.tolist() != base.gen_block(seqs).key.tolist()
+    # seed 0 is the historical stream
+    assert repr(NexmarkGenerator(rate=7000, n_keys=40, seed=0)(5)) == \
+        repr(base(5))
+
+
 @pytest.mark.parametrize("seed", [0, 5])
 def test_disordered_gen_block_matches_scalar(seed):
     gen = NexmarkGenerator(rate=10_000, n_keys=25)
