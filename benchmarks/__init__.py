@@ -1,1 +1,1 @@
-"""Benchmarks: one per paper table/figure + device tier + roofline."""
+"""Benchmarks: one per paper table/figure + device tier."""

@@ -1,5 +1,5 @@
 """Benchmark entry point: one section per paper table/figure plus the
-device tier and the roofline summary.
+device tier.
 
 Prints ``name,us_per_call,derived`` CSV rows (one per measurement) and a
 human-readable summary. ``--full`` lengthens runs; default is quick mode.
@@ -58,7 +58,7 @@ def main() -> None:
 
     from repro.jax_cache import enable_compile_cache
     enable_compile_cache()
-    from . import bench_device_tier, bench_figures, bench_latency, roofline
+    from . import bench_device_tier, bench_figures, bench_latency
 
     all_rows = []
     print("name,us_per_call,derived")
@@ -109,20 +109,10 @@ def main() -> None:
         for line in _csv_rows(rows):
             print(line, flush=True)
 
-    # roofline summary (from the dry-run artifacts, if present)
-    rl = roofline.full_table()
-    for r in rl:
-        print(f"roofline.{r['arch']}.{r['shape']},"
-              f"{max(r['compute_s'], r['memory_s'], r['collective_s']) * 1e6:.1f},"
-              f"dominant={r['dominant']};useful={r['useful_ratio']:.2f};"
-              f"bound={r['roofline_fraction_bound']:.3f};"
-              f"gib={r['temp_gib_per_chip']:.1f}", flush=True)
-
     out = pathlib.Path(__file__).resolve().parents[1] / "experiments"
     out.mkdir(exist_ok=True)
     (out / "bench_results.json").write_text(
-        json.dumps({"figures": all_rows, "roofline": rl}, indent=1,
-                   default=float))
+        json.dumps({"figures": all_rows}, indent=1, default=float))
     print(f"# wrote {out / 'bench_results.json'}", file=sys.stderr)
     # the latency section appends the per-run record (git SHA, saturation
     # A/B, paced + device percentiles) to the cumulative cross-PR log

@@ -52,6 +52,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from . import spans
 from .events import Event, EventBlock, Watermark
 from .processor import Inbox, Processor
 from .window import AggregateOperation, SlidingWindowDef, WindowResult
@@ -87,12 +88,13 @@ class DeviceWindowProcessor(Processor):
     #: ("k", key) shards + ("meta", idx) entries and rebuilt by
     #: finish_snapshot_restore).  _emit_buf flushes before the barrier,
     #: watermark cursors re-advance from replayed sources, executor/_spec
-    #: are rebuilt lazily by _ensure_executor, and _bucket_collisions is
-    #: telemetry.
+    #: are rebuilt lazily by _ensure_executor, and _bucket_collisions,
+    #: _readback_bytes and _emitting_steps are telemetry.
     EPHEMERAL_STATE = frozenset({
         "_ts", "_key", "_val", "_n", "_pending", "_emit_buf", "_steps",
         "_progress_hint", "_last_wm", "_wm_submitted", "_closed",
-        "_spec", "_bucket_collisions",
+        "_spec", "_bucket_collisions", "_readback_bytes",
+        "_emitting_steps",
     })
 
     def __init__(self, wdef: SlidingWindowDef, op: AggregateOperation,
@@ -137,6 +139,8 @@ class DeviceWindowProcessor(Processor):
         self._last_wm = -1               # highest watermark fully processed
         self._top_ts = -1                # max event ts seen (host-side)
         self._steps = 0                  # telemetry: device steps driven
+        self._readback_bytes = 0         # telemetry: bytes copied to host
+        self._emitting_steps = 0         # telemetry: steps that emitted
         self._progress_hint = False      # last _harvest_ready made progress
         self._snap_entries: Optional[List[Tuple[Any, Any]]] = None
         self._restore_frames: Dict[Any, Dict[int, float]] = {}
@@ -254,31 +258,42 @@ class DeviceWindowProcessor(Processor):
                 self._submit()
 
     # ------------------------------------------------------- device drive --
-    def _submit(self, wm_hint: Optional[int] = None) -> None:
+    def _submit(self, wm_hint: Optional[int] = None,
+                window_end: Optional[int] = None) -> None:
         """Stage the current staging buffer as one padded device batch and
         dispatch the step asynchronously; the output joins the pending
-        list as a device future."""
-        n = self._n
-        B = self.batch_size
-        wm = np.asarray(-1 if wm_hint is None else wm_hint, np.int32)
-        if n == B:
-            batch = {"ts": self._ts.copy(), "key": self._key.copy(),
-                     "value": self._val.copy(),
-                     "valid": np.ones(B, bool), "wm": wm}
-        else:
-            # pad the partial burst to the fixed device batch size
-            # (np.pad copies, so the staging buffers stay reusable)
-            pad = (0, B - n)
-            batch = {"ts": np.pad(self._ts[:n], pad),
-                     "key": np.pad(self._key[:n], pad),
-                     "value": np.pad(self._val[:n], pad),
-                     "valid": np.pad(np.ones(n, bool), pad), "wm": wm}
-        staged, cnt = self.executor.stage_batch(batch)
-        self.state, out = self.executor.step(self.state, staged,
-                                             valid_count=cnt)
-        self._pending.append((wm_hint, out))
-        self._steps += 1
-        self._n = 0
+        list as a device future.  ``window_end``: the highest window end
+        the hint closes, given on the first step that carries it (a span
+        attribute only)."""
+        tracing = spans.on
+        if tracing:
+            attrs = {"rows": self._n, "wm": -1 if wm_hint is None
+                     else wm_hint}
+            if window_end is not None:
+                attrs["window_end"] = window_end
+        with spans.span("jet.bridge.submit", **attrs) if tracing \
+                else spans.OFF:
+            n = self._n
+            B = self.batch_size
+            wm = np.asarray(-1 if wm_hint is None else wm_hint, np.int32)
+            if n == B:
+                batch = {"ts": self._ts.copy(), "key": self._key.copy(),
+                         "value": self._val.copy(),
+                         "valid": np.ones(B, bool), "wm": wm}
+            else:
+                # pad the partial burst to the fixed device batch size
+                # (np.pad copies, so the staging buffers stay reusable)
+                pad = (0, B - n)
+                batch = {"ts": np.pad(self._ts[:n], pad),
+                         "key": np.pad(self._key[:n], pad),
+                         "value": np.pad(self._val[:n], pad),
+                         "valid": np.pad(np.ones(n, bool), pad), "wm": wm}
+            staged, cnt = self.executor.stage_batch(batch)
+            self.state, out = self.executor.step(self.state, staged,
+                                                 valid_count=cnt)
+            self._pending.append((wm_hint, out))
+            self._steps += 1
+            self._n = 0
 
     @staticmethod
     def _is_ready(arr) -> bool:
@@ -303,22 +318,41 @@ class DeviceWindowProcessor(Processor):
         return not pending
 
     def _convert(self, out: Dict) -> None:
-        valid = np.asarray(out["valid"])
-        if not valid.any():
+        tracing = spans.on
+        with spans.span("jet.bridge.readback") if tracing else spans.OFF \
+                as sp:
+            valid = np.asarray(out["valid"])
+            emitting = bool(valid.any())
+            nbytes = valid.nbytes
+            if emitting:
+                ends = np.asarray(out["window_ends"])
+                res = np.asarray(out["results"])
+                nbytes += ends.nbytes + res.nbytes
+            if tracing:
+                sp.set_metadata(bytes=nbytes)
+        self._readback_bytes += nbytes
+        if not emitting:
             return
-        ends = np.asarray(out["window_ends"])
-        res = np.asarray(out["results"])
+        self._emitting_steps += 1
+        rows = np.nonzero(valid)[0].tolist()
         bmap, sentinel = self._bucket_key, self._bkey_sentinel
         buf = self._emit_buf
-        for i in np.nonzero(valid)[0].tolist():
-            row = res[i]
-            w_end = int(ends[i])
-            for b in np.nonzero(row)[0].tolist():
-                k = bmap[b]
-                key = b if k == sentinel else int(k)
-                val = _as_int_if_integral(float(row[b]))
-                buf.append(
-                    Event(w_end - 1, key, WindowResult(w_end, key, val)))
+        n0 = len(buf)
+        with spans.span("jet.bridge.convert",
+                        window_end=int(ends[rows[0]]),
+                        last_window_end=int(ends[rows[-1]])) if tracing \
+                else spans.OFF as sp:
+            for i in rows:
+                row = res[i]
+                w_end = int(ends[i])
+                for b in np.nonzero(row)[0].tolist():
+                    k = bmap[b]
+                    key = b if k == sentinel else int(k)
+                    val = _as_int_if_integral(float(row[b]))
+                    buf.append(
+                        Event(w_end - 1, key, WindowResult(w_end, key, val)))
+            if tracing:
+                sp.set_metadata(results=len(buf) - n0)
 
     def _flush_emit(self) -> bool:
         buf = self._emit_buf
@@ -361,7 +395,7 @@ class DeviceWindowProcessor(Processor):
             return True
         if wm.ts > self._wm_submitted:
             # flush staged rows + the hint in ONE wm-carrying step
-            self._submit(wm_hint=wm.ts)
+            self._submit(wm_hint=wm.ts, window_end=wm.ts // slide * slide)
             self._wm_submitted = wm.ts
         # harvest everything up to (and including) the hint step
         if not self._harvest_ready():
@@ -391,8 +425,10 @@ class DeviceWindowProcessor(Processor):
                 self._submit()
             close_wm = max(self._top_ts + self.wdef.size + self.wdef.slide,
                            self._last_wm + self.wdef.slide)
-            for _ in range(10_000):
-                self._submit(wm_hint=close_wm)
+            slide = self.wdef.slide
+            for i in range(10_000):
+                self._submit(wm_hint=close_wm, window_end=None if i
+                             else close_wm // slide * slide)
                 self._harvest_ready(block=True)
                 if not np.any(np.asarray(self.state["slot_frame"]) >= 0):
                     break
@@ -542,3 +578,9 @@ class DeviceWindowProcessor(Processor):
     def steps(self) -> int:
         """Device steps this instance has dispatched."""
         return self._steps
+
+    def telemetry(self) -> Dict[str, int]:
+        """``readback_bytes``: bytes of step output copied to the host;
+        ``emitting_steps``: harvested steps whose output held a window."""
+        return {"readback_bytes": self._readback_bytes,
+                "emitting_steps": self._emitting_steps}

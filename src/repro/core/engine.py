@@ -364,7 +364,7 @@ class ExecutionContext:
         return self.cluster.backend.execution_done(self)
 
     def stats(self) -> Dict[str, Any]:
-        return {
+        out = {
             "tasklets": len(self.tasklets),
             "links": len(self.links),
             "items_in": sum(t.items_in for t in self.tasklets),
@@ -372,6 +372,11 @@ class ExecutionContext:
             "calls": sum(t.calls for t in self.tasklets),
             "idle_calls": sum(t.idle_calls for t in self.tasklets),
         }
+        # processors' own counters (Processor.telemetry), summed
+        for t in self.tasklets:
+            for name, value in t.processor.telemetry().items():
+                out[name] = out.get(name, 0) + value
+        return out
 
 
 class Job:

@@ -14,7 +14,8 @@ hooks used by the Chandy-Lamport protocol.
 from __future__ import annotations
 
 from collections import deque
-from typing import Any, Callable, Iterable, Iterator, List, Optional, Tuple
+from typing import (Any, Callable, Dict, Iterable, Iterator, List, Optional,
+                    Tuple)
 
 from .events import DONE, Event, EventBlock, Watermark
 
@@ -220,6 +221,11 @@ class Processor:
 
     def close(self) -> None:
         """Release resources at job end."""
+
+    def telemetry(self) -> Dict[str, int]:
+        """Counters of this instance's own, by name; the job's
+        ``ExecutionContext.stats()`` sums each over all instances."""
+        return {}
 
 
 # ---------------------------------------------------------------------------

@@ -21,6 +21,7 @@ from typing import Any, Callable, Iterable, List, Optional, Sequence, Tuple
 import numpy as np
 
 from .events import Event, EventBlock, Watermark, MAX_TIME, MIN_TIME
+from . import spans
 from .processor import Inbox, Processor
 from .watermark import EventTimePolicy
 
@@ -245,7 +246,10 @@ class PacedGeneratorSource(Processor):
                 if not len(seqs):
                     return False
                 n = len(seqs)
-        blk = self._gen_block(seqs)
+        with spans.span("jet.source.generate", events=n,
+                        lag_us=int(overdue / self.rate * 1e6)) \
+                if spans.on else spans.OFF:
+            blk = self._gen_block(seqs)
         ts = blk.ts
         pol = self.policy
         lag = pol.lag

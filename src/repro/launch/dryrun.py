@@ -11,9 +11,7 @@ Usage::
     PYTHONPATH=src python -m repro.launch.dryrun --all [--multi-pod]
 
 Results (memory analysis, HLO flops/bytes, per-collective byte counts) are
-appended to ``experiments/dryrun/<arch>__<shape>__<mesh>.json`` — the
-roofline table in EXPERIMENTS.md is generated from these files by
-``benchmarks/roofline.py``.
+appended to ``experiments/dryrun/<arch>__<shape>__<mesh>.json``.
 """
 
 import argparse
@@ -64,11 +62,9 @@ def collective_bytes(hlo_text: str, scan_multipliers=None) -> dict:
     ring-algorithm constant factors; this is the standard dry-run proxy).
 
     CPU-backend caveat (documented in EXPERIMENTS.md): instructions inside
-    a ``while`` (scan) body are counted ONCE here; the roofline script
-    applies the statically-known trip counts (``scan_multipliers`` maps
-    computation-name substrings to multipliers) when deriving per-step
-    traffic.  We also report the per-computation breakdown so that
-    correction is possible downstream.
+    a ``while`` (scan) body are counted ONCE here; per-step traffic needs
+    the statically-known trip counts applied downstream.  We also report
+    the per-computation breakdown so that correction is possible.
     """
     per_kind = {}
     per_comp = {}

@@ -12,6 +12,7 @@ from __future__ import annotations
 
 from typing import Callable, Dict, Optional
 
+from ..core import spans
 from ..core.events import MIN_TIME, Event, Watermark, block_form
 from ..core.pipeline import Pipeline
 from ..core.processor import Inbox, Processor
@@ -145,28 +146,32 @@ class MaxPerWindowProcessor(Processor):
     def process(self, ordinal: int, inbox: Inbox) -> None:
         best = self.best
         get = best.get
-        for ev in inbox:
-            wr = ev.value
-            cur = get(wr.window_end)
-            if cur is None or wr.value > cur[1]:
-                best[wr.window_end] = (wr.key, wr.value)
+        with spans.span("jet.hot_items.process", items=len(inbox)) \
+                if spans.on else spans.OFF:
+            for ev in inbox:
+                wr = ev.value
+                cur = get(wr.window_end)
+                if cur is None or wr.value > cur[1]:
+                    best[wr.window_end] = (wr.key, wr.value)
         inbox.clear()
 
     def try_process_watermark(self, wm: Watermark) -> bool:
         # strict: a result carries ts == w - 1 and items with ts == wm may
         # still arrive after the watermark
-        ready = sorted(w for w in self.best if w - 1 < wm.ts)
-        for w in ready:
-            key, count = self.best[w]
-            if not self.outbox.offer(Event(w - 1, key, (w, key, count))):
-                return False
-            del self.best[w]
-        return True
+        return self._offer_answers(
+            sorted(w for w in self.best if w - 1 < wm.ts))
 
     def complete(self) -> bool:
-        for w in sorted(self.best):
+        return self._offer_answers(sorted(self.best))
+
+    def _offer_answers(self, ready) -> bool:
+        for w in ready:
             key, count = self.best[w]
-            if not self.outbox.offer(Event(w - 1, key, (w, key, count))):
+            with spans.span("jet.hot_items.emit", window_end=w) \
+                    if spans.on else spans.OFF:
+                offered = self.outbox.offer(Event(w - 1, key,
+                                                  (w, key, count)))
+            if not offered:
                 return False
             del self.best[w]
         return True

@@ -11,7 +11,7 @@ import pytest
 from repro.core import (CollectorSink, JetCluster, JobConfig,
                         PacedGeneratorSource, VirtualClock,
                         GUARANTEE_EXACTLY_ONCE)
-from repro.core.engine import JOB_COMPLETED
+from repro.core.engine import JOB_COMPLETED, JOB_FAILED
 from repro.nexmark import (DisorderedNexmarkGenerator, NexmarkGenerator,
                            queries)
 
@@ -39,7 +39,8 @@ def _run_q5(backend, block_size=0, disorder=0, wm_lag=None, n_nodes=1,
     killed = False
     try:
         for _ in range(4_000_000):
-            if job.status == JOB_COMPLETED:
+            # a failed job never resumes: stop stepping and fail at once
+            if job.status in (JOB_COMPLETED, JOB_FAILED):
                 break
             cluster.step()
             if (kill_at_result is not None and not killed
