@@ -31,11 +31,23 @@ the device tier's fused accumulate+emit step (:mod:`repro.streaming`):
   emissions are harvested and the device emission front has passed the
   watermark — downstream still sees every result *before* the watermark
   that closed it, exactly the host contract.
-* **Unpacking** — harvested ``(window_ends, results)`` rows become
-  ``Event(w_end - 1, key, WindowResult(w_end, key, value))`` per nonzero
-  bucket, the exact shape the host two-stage combiner emits (near-integer
-  values collapse to int: counting/integer-sum aggregates compare equal
-  to the host path bit-for-bit up to f32's 2**24 integer range).
+* **Unpacking** — every step's ``valid`` mask comes back; only a step
+  that emitted copies more.  The output takes one of two forms, fixed at
+  construction (``emission``):
+
+  - ``"per_key"`` (the default): harvested ``(window_ends, results)``
+    rows become ``Event(w_end - 1, key, WindowResult(w_end, key, value))``
+    per nonzero bucket, the exact shape the host two-stage combiner emits
+    (near-integer values collapse to int: counting/integer-sum aggregates
+    compare equal to the host path bit-for-bit up to f32's 2**24 integer
+    range).
+  - ``"top1"``, for a consumer that keeps only the highest count per
+    window end (Q5's hot-items stage, which merges partials by max): the
+    device reduces each window end to its highest count and the lowest
+    bucket holding it, and only that packed ``(3, rows)`` int32 array
+    comes back.  Each row with a count above 0 becomes one such
+    ``WindowResult`` event, this instance's partial; ``reduced_windows``
+    counts them.  Counting only: a sum cannot tell absent from zero.
 * **Snapshots** — barriers align to step boundaries: staged rows flush as
   a final step, emission catches up to the last processed watermark
   (identical across instances — the coalesced watermark sequence is), and
@@ -72,6 +84,11 @@ class DeviceWindowProcessor(Processor):
     ingestion (``accepts_blocks`` stays False and the tasklet shim
     explodes blocks at the queue boundary).
 
+    ``emission`` picks the output form (module docstring, "Unpacking"):
+    ``"per_key"`` sends every key's result; ``"top1"`` sends one partial
+    per window end, for a plan whose only consumer keeps the highest
+    count (``nexmark.queries.q5`` with its hot-items stage).
+
     Known divergence from the host plan: the device pane matrix cannot
     distinguish "no events" from "events summing to exactly 0", so a
     summing window whose total is 0 emits nothing here while the host
@@ -89,22 +106,27 @@ class DeviceWindowProcessor(Processor):
     #: finish_snapshot_restore).  _emit_buf flushes before the barrier,
     #: watermark cursors re-advance from replayed sources, executor/_spec
     #: are rebuilt lazily by _ensure_executor, and _bucket_collisions,
-    #: _readback_bytes and _emitting_steps are telemetry.
+    #: _readback_bytes, _emitting_steps and _reduced_windows are
+    #: telemetry.
     EPHEMERAL_STATE = frozenset({
         "_ts", "_key", "_val", "_n", "_pending", "_emit_buf", "_steps",
         "_progress_hint", "_last_wm", "_wm_submitted", "_closed",
         "_spec", "_bucket_collisions", "_readback_bytes",
-        "_emitting_steps",
+        "_emitting_steps", "_reduced_windows",
     })
 
     def __init__(self, wdef: SlidingWindowDef, op: AggregateOperation,
                  n_key_buckets: int = 1024, batch_size: int = 1024,
                  max_windows_per_step: int = 8, ring_margin: int = 8,
-                 emit_rounds: int = 0):
+                 emit_rounds: int = 0, emission: str = "per_key"):
         if op.kind not in ("count", "sum"):
             raise ValueError(
                 "device placement supports counting()/summing() aggregates "
                 f"(got kind={op.kind!r}); keep other ops on the host")
+        if emission == "top1" and op.kind != "count":
+            raise ValueError(
+                "the top-1 emission form needs counting(): a sum's zero and "
+                "an absent key look alike on the device")
         self.wdef = wdef
         self.op = op
         self.n_key_buckets = n_key_buckets
@@ -112,6 +134,7 @@ class DeviceWindowProcessor(Processor):
         self.max_windows_per_step = max_windows_per_step
         self.ring_margin = ring_margin
         self.emit_rounds = emit_rounds
+        self.emission = emission
         # blocks are only useful when the value column vectorizes
         self.accepts_blocks = (op.kind == "count"
                                or op.block_get is not None)
@@ -141,6 +164,7 @@ class DeviceWindowProcessor(Processor):
         self._steps = 0                  # telemetry: device steps driven
         self._readback_bytes = 0         # telemetry: bytes copied to host
         self._emitting_steps = 0         # telemetry: steps that emitted
+        self._reduced_windows = 0        # telemetry: top-1 partials sent
         self._progress_hint = False      # last _harvest_ready made progress
         self._snap_entries: Optional[List[Tuple[Any, Any]]] = None
         self._restore_frames: Dict[Any, Dict[int, float]] = {}
@@ -173,7 +197,7 @@ class DeviceWindowProcessor(Processor):
             n_key_buckets=self.n_key_buckets,
             max_windows_per_step=self.max_windows_per_step,
             ring_margin=self.ring_margin, emit_rounds=self.emit_rounds,
-            frontier_from_data=False)
+            frontier_from_data=False, emission=self.emission)
         self.executor = StreamExecutor(
             StreamJobConfig(window=spec, batch_size=self.batch_size))
         self.state = self.executor.init_state()
@@ -319,15 +343,21 @@ class DeviceWindowProcessor(Processor):
 
     def _convert(self, out: Dict) -> None:
         tracing = spans.on
+        top1 = self.emission == "top1"
         with spans.span("jet.bridge.readback") if tracing else spans.OFF \
                 as sp:
             valid = np.asarray(out["valid"])
             emitting = bool(valid.any())
             nbytes = valid.nbytes
             if emitting:
-                ends = np.asarray(out["window_ends"])
-                res = np.asarray(out["results"])
-                nbytes += ends.nbytes + res.nbytes
+                if top1:
+                    top = np.asarray(out["top"])
+                    ends = top[0]
+                    nbytes += top.nbytes
+                else:
+                    ends = np.asarray(out["window_ends"])
+                    res = np.asarray(out["results"])
+                    nbytes += ends.nbytes + res.nbytes
             if tracing:
                 sp.set_metadata(bytes=nbytes)
         self._readback_bytes += nbytes
@@ -342,15 +372,25 @@ class DeviceWindowProcessor(Processor):
                         window_end=int(ends[rows[0]]),
                         last_window_end=int(ends[rows[-1]])) if tracing \
                 else spans.OFF as sp:
-            for i in rows:
-                row = res[i]
-                w_end = int(ends[i])
-                for b in np.nonzero(row)[0].tolist():
-                    k = bmap[b]
-                    key = b if k == sentinel else int(k)
-                    val = _as_int_if_integral(float(row[b]))
-                    buf.append(
-                        Event(w_end - 1, key, WindowResult(w_end, key, val)))
+            if top1:
+                # one partial per window end: this instance's hot item
+                for w_end, b, count in top[:, rows].T.tolist():
+                    if count > 0:
+                        k = bmap[b]
+                        key = b if k == sentinel else int(k)
+                        buf.append(Event(w_end - 1, key,
+                                         WindowResult(w_end, key, count)))
+                self._reduced_windows += len(buf) - n0
+            else:
+                for i in rows:
+                    row = res[i]
+                    w_end = int(ends[i])
+                    for b in np.nonzero(row)[0].tolist():
+                        k = bmap[b]
+                        key = b if k == sentinel else int(k)
+                        val = _as_int_if_integral(float(row[b]))
+                        buf.append(Event(w_end - 1, key,
+                                         WindowResult(w_end, key, val)))
             if tracing:
                 sp.set_metadata(results=len(buf) - n0)
 
@@ -581,6 +621,9 @@ class DeviceWindowProcessor(Processor):
 
     def telemetry(self) -> Dict[str, int]:
         """``readback_bytes``: bytes of step output copied to the host;
-        ``emitting_steps``: harvested steps whose output held a window."""
+        ``emitting_steps``: harvested steps whose output held a window;
+        ``reduced_windows``: window ends the device reduced to one partial
+        (top-1 form; 0 in the per-key form)."""
         return {"readback_bytes": self._readback_bytes,
-                "emitting_steps": self._emitting_steps}
+                "emitting_steps": self._emitting_steps,
+                "reduced_windows": self._reduced_windows}

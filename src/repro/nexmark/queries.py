@@ -200,11 +200,18 @@ def q5(source, sink, window_ms: int = 10_000, slide_ms: int = 10,
 
     ``placement="device"`` swaps the host two-stage plan for the
     device-offloaded window vertex (EventBlocks pack into padded device
-    batches, the compiled StreamExecutor aggregates) — same WindowResult
-    stream, devices doing the math.  ``device`` forwards processor
-    overrides; size ``n_key_buckets`` at or above the auction key space
-    for per-auction-exact results.
+    batches, the compiled StreamExecutor aggregates), devices doing the
+    math.  ``device`` forwards processor overrides; size ``n_key_buckets``
+    at or above the auction key space for per-auction-exact results.
+    Without the second level the vertex sends the same per-auction
+    WindowResult stream as the host plan.  With it, the max-per-window
+    stage is the counts' only consumer, so the vertex takes the top-1
+    emission form: each instance sends one partial per window end, its
+    auction with the most bids (the lowest bucket on ties), and the
+    stage merges the partials by max into the same answer.
     """
+    if with_global_max and placement == "device":
+        device = dict(device or {}, emission="top1")
     p = Pipeline.create()
     counts = (p.read_from(source, name="bids")
                 .filter(is_bid)

@@ -34,7 +34,8 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..compat import shard_map
-from .window import VectorWindowSpec, accumulate, emit, window_state_init
+from .window import (EMIT_PER_KEY, VectorWindowSpec, accumulate, emit,
+                     window_state_init)
 
 ACK_INTERVAL_S = 0.1
 WINDOW_FILL_FACTOR = 3
@@ -110,6 +111,10 @@ class StreamExecutor:
                 return emit(spec, state)
             return step1
 
+        if spec.emission != EMIT_PER_KEY:
+            raise ValueError(
+                f"emission={spec.emission!r} runs on a single device only; "
+                "the SPMD steps emit per key")
         mesh = self.mesh
         K_loc = spec.n_key_buckets // n_shards
         if self.cfg.exchange == "route":

@@ -11,7 +11,10 @@ pane-based plan as the host tier (core/window.py), vectorized:
   one-hot matmul"),
 * **combine + emit** (Jet stage 2): when the watermark crosses a slide
   boundary, the window result per key is ``panes_ring @ window_mask`` —
-  one matvec per emitted window.
+  one matvec per emitted window.  ``VectorWindowSpec.emission`` picks
+  what leaves the step: every key's result (``"per_key"``), or only the
+  highest result per window end and its bucket (``"top1"``, for a
+  consumer that wants the hot item alone; single device only).
 
 Frame/window convention: frame ``f`` covers event time
 ``[f*slide, (f+1)*slide)``; the window whose LAST frame is ``L`` covers
@@ -53,6 +56,10 @@ import jax.numpy as jnp
 #: sentinel for "no frame / uninitialised emission front" (int32-safe)
 _FAR = 2**30
 
+#: emission forms (``VectorWindowSpec.emission``)
+EMIT_PER_KEY = "per_key"
+EMIT_TOP1 = "top1"
+
 
 @dataclasses.dataclass(frozen=True)
 class VectorWindowSpec:
@@ -70,6 +77,15 @@ class VectorWindowSpec:
     #: max emission rounds per step (0 = auto: ceil(ring_len / E), enough
     #: output rows to retire every live frame's next window in one step)
     emit_rounds: int = 0
+    #: what a step emits per window end: ``"per_key"`` every bucket's
+    #: result (``results``, ``(EB, K)``), or ``"top1"`` only the highest
+    #: result and the lowest bucket holding it (``top``, ``(3, EB)``)
+    emission: str = EMIT_PER_KEY
+
+    def __post_init__(self):
+        if self.emission not in (EMIT_PER_KEY, EMIT_TOP1):
+            raise ValueError(f"unknown emission form {self.emission!r}; "
+                             f"use {EMIT_PER_KEY!r} or {EMIT_TOP1!r}")
 
     @property
     def frames_per_window(self) -> int:
@@ -92,8 +108,9 @@ class VectorWindowSpec:
 
     @property
     def emit_buffer_rows(self) -> int:
-        """Rows in a step's emission output (``results``/``window_ends``/
-        ``valid`` leading dimension)."""
+        """Rows in a step's emission output: one per emitted window end
+        (the leading dimension of ``results``/``window_ends``/``valid``,
+        the second of ``top``)."""
         return self.max_windows_per_step * self.emit_rounds_resolved
 
 
@@ -167,6 +184,14 @@ def emit(spec: VectorWindowSpec, state: Dict
     """Jet stage 2, vectorized: emit window results with end <= watermark;
     evict the frame each emission retires.
 
+    Output per row (window end): ``valid``, and in the per-key form
+    ``window_ends`` and ``results`` (every bucket's result); in the top-1
+    form ``top``, int32 ``(3, EB)``: the window end (-1 where the row is
+    not valid), the lowest bucket holding the row's highest result, and
+    that result, rounded (exact for counts below 2**24).  Both forms
+    compute the same ``(E, K)`` results per round; the top-1 form keeps
+    only their maximum, so the ``(EB, K)`` matrix never leaves the step.
+
     Emission runs in rounds of ``max_windows_per_step`` windows (one
     ``(E, R) @ (R, K)`` matmul per round) inside a bounded
     ``lax.while_loop`` that stops when the front passes the watermark or
@@ -210,7 +235,12 @@ def emit(spec: VectorWindowSpec, state: Dict
                     state["next_emit"])
     ne0 = fast_forward(ne0, slot_frame0)
 
-    res0 = jnp.zeros((EB, panes0.shape[1]), panes0.dtype)
+    top1 = spec.emission == EMIT_TOP1
+    if top1:
+        # (bucket, result) of each row's maximum
+        res0 = (jnp.zeros((EB,), jnp.int32), jnp.zeros((EB,), jnp.int32))
+    else:
+        res0 = jnp.zeros((EB, panes0.shape[1]), panes0.dtype)
     ends0 = jnp.zeros((EB,), jnp.int32)
     val0 = jnp.zeros((EB,), bool)
 
@@ -243,7 +273,14 @@ def emit(spec: VectorWindowSpec, state: Dict
         # the ready rows are a prefix of the E candidates (w_ends are
         # ascending), so advancing the cursor by n_emitted lets the next
         # round overwrite only the not-ready tail
-        res = jax.lax.dynamic_update_slice(res, results, (count, 0))
+        if top1:
+            # argmax: the lowest bucket on ties
+            best = jnp.argmax(results, axis=1).astype(jnp.int32)
+            high = jnp.round(jnp.max(results, axis=1)).astype(jnp.int32)
+            res = (jax.lax.dynamic_update_slice(res[0], best, (count,)),
+                   jax.lax.dynamic_update_slice(res[1], high, (count,)))
+        else:
+            res = jax.lax.dynamic_update_slice(res, results, (count, 0))
         ends = jax.lax.dynamic_update_slice(ends, w_ends, (count,))
         val = jax.lax.dynamic_update_slice(val, ready, (count,))
         count = count + n_emitted
@@ -257,6 +294,9 @@ def emit(spec: VectorWindowSpec, state: Dict
     new_next = jnp.where(ne_f < _FAR, ne_f, state["next_emit"])
     out_state = dict(state, panes=panes, slot_frame=slot_frame,
                      next_emit=new_next)
+    if top1:
+        return out_state, {"top": jnp.stack(
+            [jnp.where(val, ends, -1), res[0], res[1]]), "valid": val}
     return out_state, {"results": res, "window_ends": ends, "valid": val}
 
 

@@ -78,6 +78,35 @@ def test_single_chip_step_compiles_and_fits(one_chip):
     assert 0 < total < V5E_HBM
 
 
+def test_single_chip_top1_step_holds_no_result_matrix(one_chip):
+    """The top-1 form at the q5-jet cell's shape (10 s by 20 ms, 16,384
+    buckets, batch 8,192): the step sends (3, 512) int32, and no
+    (512, 16,384) result matrix is left in the compiled program."""
+    shapes = {}
+    for emission in ("per_key", "top1"):
+        spec = VectorWindowSpec(size_ms=10_000, slide_ms=20,
+                                n_key_buckets=BUCKETS, max_windows_per_step=8,
+                                ring_margin=8, frontier_from_data=False,
+                                emission=emission)
+        ex = StreamExecutor(StreamJobConfig(window=spec, batch_size=8192))
+        state, batch = _shapes(spec, lambda k: one_chip, lambda k: one_chip)
+        batch = {k: jax.ShapeDtypeStruct((8192,) if v.shape else (),
+                                         v.dtype, sharding=one_chip)
+                 for k, v in batch.items()}
+        lowered = ex._step.lower(state, batch)
+        dots = [line for line in lowered.as_text().splitlines()
+                if "dot_general" in line]
+        assert dots and all("HIGHEST" in line for line in dots), dots
+        _st, out = jax.eval_shape(ex._step, state, batch)
+        shapes[emission] = {k: (v.shape, v.dtype) for k, v in out.items()}
+        matrix = f"f32[{spec.emit_buffer_rows},{BUCKETS}]"
+        assert (matrix in lowered.compile().as_text()) == \
+            (emission == "per_key")
+    assert shapes["top1"] == {"top": ((3, 512), jnp.int32),
+                              "valid": ((512,), jnp.bool_)}
+    assert shapes["per_key"]["results"] == ((512, BUCKETS), jnp.float32)
+
+
 @pytest.mark.parametrize("exchange,collective",
                          [("reduce", "reduce-scatter"),
                           ("route", "all-to-all")])
