@@ -48,6 +48,11 @@ the device tier's fused accumulate+emit step (:mod:`repro.streaming`):
     comes back.  Each row with a count above 0 becomes one such
     ``WindowResult`` event, this instance's partial; ``reduced_windows``
     counts them.  Counting only: a sum cannot tell absent from zero.
+
+  ``key_results`` counts the per-key form's results, ``readback_bytes``
+  the bytes copied back; together they give the bytes read back per
+  result delivered.  Results wait in a buffer until the outbox takes
+  them (``_flush_emit``, spanned as ``jet.bridge.flush``).
 * **Snapshots** — barriers align to step boundaries: staged rows flush as
   a final step, emission catches up to the last processed watermark
   (identical across instances — the coalesced watermark sequence is), and
@@ -106,13 +111,13 @@ class DeviceWindowProcessor(Processor):
     #: finish_snapshot_restore).  _emit_buf flushes before the barrier,
     #: watermark cursors re-advance from replayed sources, executor/_spec
     #: are rebuilt lazily by _ensure_executor, and _bucket_collisions,
-    #: _readback_bytes, _emitting_steps and _reduced_windows are
-    #: telemetry.
+    #: _readback_bytes, _emitting_steps, _reduced_windows and
+    #: _key_results are telemetry.
     EPHEMERAL_STATE = frozenset({
         "_ts", "_key", "_val", "_n", "_pending", "_emit_buf", "_steps",
         "_progress_hint", "_last_wm", "_wm_submitted", "_closed",
         "_spec", "_bucket_collisions", "_readback_bytes",
-        "_emitting_steps", "_reduced_windows",
+        "_emitting_steps", "_reduced_windows", "_key_results",
     })
 
     def __init__(self, wdef: SlidingWindowDef, op: AggregateOperation,
@@ -165,6 +170,7 @@ class DeviceWindowProcessor(Processor):
         self._readback_bytes = 0         # telemetry: bytes copied to host
         self._emitting_steps = 0         # telemetry: steps that emitted
         self._reduced_windows = 0        # telemetry: top-1 partials sent
+        self._key_results = 0            # telemetry: per-key results sent
         self._progress_hint = False      # last _harvest_ready made progress
         self._snap_entries: Optional[List[Tuple[Any, Any]]] = None
         self._restore_frames: Dict[Any, Dict[int, float]] = {}
@@ -391,16 +397,24 @@ class DeviceWindowProcessor(Processor):
                         val = _as_int_if_integral(float(row[b]))
                         buf.append(Event(w_end - 1, key,
                                          WindowResult(w_end, key, val)))
+                self._key_results += len(buf) - n0
             if tracing:
                 sp.set_metadata(results=len(buf) - n0)
 
     def _flush_emit(self) -> bool:
+        """Move buffered results into the outbox until it refuses one;
+        True once the buffer is empty."""
         buf = self._emit_buf
-        while buf:
-            if not self.outbox.offer(buf[0]):
-                return False
-            buf.popleft()
-        return True
+        n = len(buf)
+        tracing = spans.on and n > 0
+        with spans.span("jet.bridge.flush") if tracing else spans.OFF as sp:
+            while buf:
+                if not self.outbox.offer(buf[0]):
+                    break
+                buf.popleft()
+            if tracing:
+                sp.set_metadata(results=n - len(buf), left=len(buf))
+        return not buf
 
     def poll_async(self) -> bool:
         """Non-blocking pump the tasklet calls every slice: harvest device
@@ -623,7 +637,9 @@ class DeviceWindowProcessor(Processor):
         """``readback_bytes``: bytes of step output copied to the host;
         ``emitting_steps``: harvested steps whose output held a window;
         ``reduced_windows``: window ends the device reduced to one partial
-        (top-1 form; 0 in the per-key form)."""
+        (top-1 form; 0 in the per-key form); ``key_results``: per-key
+        ``WindowResult`` events sent (per-key form; 0 in the top-1 form)."""
         return {"readback_bytes": self._readback_bytes,
                 "emitting_steps": self._emitting_steps,
-                "reduced_windows": self._reduced_windows}
+                "reduced_windows": self._reduced_windows,
+                "key_results": self._key_results}

@@ -42,6 +42,10 @@ Spans (every span of one window end carries the same ``window_end``):
 ``jet.bridge.convert``    the loop that turns counts into results
                           (``results``, ``window_end`` and
                           ``last_window_end``)
+``jet.bridge.flush``      ``DeviceWindowProcessor._flush_emit``, opened
+                          only while results wait: moving them into the
+                          outbox (``results`` moved, ``left`` still
+                          buffered where the outbox refused)
 ``jet.hot_items.process`` ``MaxPerWindowProcessor.process`` (``items``)
 ``jet.hot_items.emit``    one answer offered (``window_end``)
 ``jet.gc``                a garbage collection (``generation``,

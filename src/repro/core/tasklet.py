@@ -708,6 +708,11 @@ class ProcessorTasklet:
                 return False
             self._wm_processed = True
             self._flush_outbox()
+        if self._pend_pos < len(self._pend_items) or len(self.outbox):
+            # items stuck behind one full queue may be bound for queues
+            # with room: broadcasting now would put the watermark ahead of
+            # them there, before results of the windows it closes
+            return False
         for c in self.collectors:
             if not c.broadcast(wm):
                 return False

@@ -340,3 +340,51 @@ def test_batched_drain_equivalent_without_guarantee(monkeypatch):
                 for ev in out]
 
     assert sorted(run(256)) == sorted(run(1))
+
+
+def test_watermark_waits_for_what_it_closed_on_every_queue():
+    """A watermark leaves only after every item its processor emitted for
+    it: items stuck behind a full queue may be bound for a queue with room,
+    where a broadcast would otherwise put the watermark ahead of them."""
+    from repro.core.processor import Processor
+    from repro.core.tasklet import (GUARANTEE_NONE, InQueue,
+                                    ProcessorTasklet, SnapshotContext)
+
+    p2q = [pid % 2 for pid in range(PARTITION_COUNT)]
+    key0, key1 = (next(k for k in range(100)
+                       if p2q[hash(k) % PARTITION_COUNT] == q)
+                  for q in (0, 1))
+
+    class Closer(Processor):
+        def try_process_watermark(self, wm):
+            # the results the watermark closes: three for queue 0 (which
+            # holds two), then one for queue 1
+            for i in range(3):
+                self.outbox.offer(Event(wm.ts - 1, key0, i))
+            self.outbox.offer(Event(wm.ts - 1, key1, 3))
+            return True
+
+    src = SPSCQueue(16)
+    out = [SPSCQueue(2), SPSCQueue(1024)]
+    t = ProcessorTasklet(
+        "w", Closer(), [InQueue(src, 0)],
+        [EdgeCollector(out, Routing.PARTITIONED, lambda ev: ev.key, p2q)],
+        SnapshotContext(GUARANTEE_NONE), "w", 0)
+    t.processor.init(t.outbox, None)
+    assert src.offer(Watermark(10))
+    t.call()
+    # queue 0 is full and nobody reads it yet: queue 1 must not see the
+    # watermark before the item that precedes it in the outbox
+    got1 = out[1].poll_many(64)
+    assert not any(isinstance(x, Watermark) for x in got1)
+    got0 = []
+    for _ in range(20):
+        got0.extend(out[0].poll_many(64))
+        got1.extend(out[1].poll_many(64))
+        t.call()
+    got0.extend(out[0].poll_many(64))
+    got1.extend(out[1].poll_many(64))
+    assert [x.value for x in got0[:3]] == [0, 1, 2]
+    assert isinstance(got0[3], Watermark)
+    assert [x.value for x in got1[:1]] == [3]
+    assert isinstance(got1[1], Watermark)

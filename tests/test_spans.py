@@ -4,7 +4,8 @@ counters.
 Off, the switch builds no annotation and leaves ``gc.callbacks`` alone.
 On, under ``jax.profiler`` on the CPU backend, a device-placed NEXMark Q5
 job with its hot-items stage leaves one submit -> convert -> emit chain
-per answered window end, all carrying the same ``window_end``.
+per answered window end, all carrying the same ``window_end``; without
+that stage, the drain of its per-key results into the outbox is spanned.
 """
 
 import gc
@@ -143,6 +144,36 @@ def test_every_answer_has_one_chain_with_its_window_end(tmp_path,
     collections = named("jet.gc")
     assert collections and {"generation", "collected"} <= \
         set(collections[-1][3])
+
+
+@pytest.mark.parametrize("threads", [1, 2])
+def test_flush_spans_carry_every_per_key_result(tmp_path, spans_off,
+                                                monkeypatch, threads):
+    """Per-key form: the ``results`` of ``jet.bridge.flush`` sum to
+    ``key_results``, and a flush of an empty buffer opens no span."""
+    real = DeviceWindowProcessor._flush_emit
+    calls = []
+
+    def flush(self):
+        calls.append(len(self._emit_buf))
+        return real(self)
+    monkeypatch.setattr(DeviceWindowProcessor, "_flush_emit", flush)
+    jax.profiler.start_trace(str(tmp_path))
+    spans.enable()
+    try:
+        job, out = _q5_job(with_global_max=False, threads=threads)
+    finally:
+        spans.disable()
+        jax.profiler.stop_trace()
+    flushes = [e[3] for e in _program_spans(tmp_path)
+               if e[2] == "jet.bridge.flush"]
+    assert sum(f["results"] for f in flushes) == \
+        job.execution.stats()["key_results"] == len(out) > 0
+    assert all(f["results"] + f["left"] > 0 for f in flushes)
+    # every call that found results opened one span, and the calls on an
+    # empty buffer (the watermark path's) opened none
+    assert len(flushes) == sum(1 for n in calls if n)
+    assert 0 in calls
 
 
 def test_readback_counters_count_each_harvested_step(spans_off):

@@ -7,7 +7,8 @@ per-key form as it was, and be refused where it cannot hold (SPMD steps,
 sums).  Through the bridge, device-placed NEXMark Q5 with its hot-items
 stage must answer every window end with the host plan's highest count and
 an auction that holds it, with one or two window instances, through a
-node kill, and count each partial it sends in ``reduced_windows``.
+node kill, and count each partial it sends in ``reduced_windows``; the
+per-key form counts each result it sends in ``key_results``.
 """
 
 import jax
@@ -256,6 +257,34 @@ def test_reduced_windows_counts_each_window_end_emitted():
     tel = p.telemetry()
     assert tel["readback_bytes"] == \
         p.steps * rows + tel["emitting_steps"] * 3 * rows * 4
+
+
+@pytest.mark.parametrize("threads", [1, 2])
+def test_key_results_counts_every_per_key_result_sent(threads):
+    """The per-key form: ``key_results`` is what the sink received, one
+    result per nonzero (window end, auction) count of the host plan."""
+    counts = _per_key_counts("dense")
+    job, out = _run_q5("device", "dense", with_global_max=False,
+                       threads=threads)
+    procs = _devices(job)
+    assert len(procs) == threads
+    assert all(p.emission == "per_key" for p in procs)
+    sent = sum(p.telemetry()["key_results"] for p in procs)
+    assert job.execution.stats()["key_results"] == sent == len(out)
+    ends = {ev.value.window_end for ev in out}
+    due = {wk: c for wk, c in counts.items() if wk[0] in ends}
+    got = {(ev.value.window_end, ev.value.key): ev.value.value for ev in out}
+    assert got == due and sent == len(due) > 500
+    assert all(p.telemetry()["reduced_windows"] == 0 for p in procs)
+
+
+@pytest.mark.parametrize("threads", [1, 2])
+def test_key_results_is_zero_in_the_top1_form(threads):
+    job, out = _run_q5("device", "sparse", threads=threads)
+    procs = _devices(job)
+    assert all(p.emission == "top1" for p in procs) and len(out) > 20
+    assert [p.telemetry()["key_results"] for p in procs] == [0] * threads
+    assert job.execution.stats()["key_results"] == 0
 
 
 def test_q5_hot_items_device_through_kill_node():
