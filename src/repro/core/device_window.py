@@ -35,9 +35,13 @@ the device tier's fused accumulate+emit step (:mod:`repro.streaming`):
   that emitted copies more.  The output takes one of two forms, fixed at
   construction (``emission``):
 
-  - ``"per_key"`` (the default): harvested ``(window_ends, results)``
-    rows become ``Event(w_end - 1, key, WindowResult(w_end, key, value))``
-    per nonzero bucket, the exact shape the host two-stage combiner emits
+  - ``"per_key"`` (the default): only the emitted prefix of
+    ``(window_ends, results)`` comes back — the rows up to the last valid
+    one, rounded up to a power of two (capped at ``emit_buffer_rows``, so
+    a few compiled slice shapes, all warmed in ``init``), sliced on the
+    device and copied in one transfer.  Each valid row becomes
+    ``Event(w_end - 1, key, WindowResult(w_end, key, value))`` per nonzero
+    bucket, the exact shape the host two-stage combiner emits
     (near-integer values collapse to int: counting/integer-sum aggregates
     compare equal to the host path bit-for-bit up to f32's 2**24 integer
     range).
@@ -64,6 +68,7 @@ the device tier's fused accumulate+emit step (:mod:`repro.streaming`):
 
 from __future__ import annotations
 
+import functools
 from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -77,6 +82,37 @@ from .window import AggregateOperation, SlidingWindowDef, WindowResult
 def _as_int_if_integral(v: float):
     r = round(v)
     return int(r) if abs(v - r) < 1e-6 else float(v)
+
+
+def _row_buckets(cap: int) -> List[int]:
+    """The row counts a per-key readback rounds up to: the powers of two
+    below ``cap`` (a step's ``emit_buffer_rows``), then ``cap`` itself."""
+    return sorted({min(1 << i, cap) for i in range(cap.bit_length() + 1)})
+
+
+def _row_bucket(n: int, cap: int) -> int:
+    """The smallest of :func:`_row_buckets` that holds ``n`` rows."""
+    return min(1 << (n - 1).bit_length(), cap)
+
+
+@functools.cache
+def _slicer():
+    """One jitted slice shared by every instance in the process: ``rows``
+    is static, so each row bucket compiles once."""
+    import jax
+    return jax.jit(lambda res, ends, rows: (res[:rows], ends[:rows]),
+                   static_argnums=2)
+
+
+def _leading_rows(out: Dict, rows: int) -> Tuple[np.ndarray, np.ndarray]:
+    """``results[:rows]`` and ``window_ends[:rows]`` of a per-key step
+    output on the host: sliced on the device, then copied in one transfer
+    (an output already on the host is sliced in place)."""
+    res, ends = out["results"], out["window_ends"]
+    if isinstance(res, np.ndarray):
+        return res[:rows], ends[:rows]
+    import jax
+    return jax.device_get(_slicer()(res, ends, rows))
 
 
 class DeviceWindowProcessor(Processor):
@@ -192,6 +228,10 @@ class DeviceWindowProcessor(Processor):
         self.state, out = self.executor.step(self.state, staged,
                                              valid_count=cnt)
         np.asarray(out["valid"])        # block: compilation finished
+        if self.emission == "per_key":
+            # and every slice the per-key readback may ask for
+            for rows in _row_buckets(self._spec.emit_buffer_rows):
+                _leading_rows(out, rows)
 
     def _ensure_executor(self) -> None:
         if self.executor is not None:
@@ -353,24 +393,25 @@ class DeviceWindowProcessor(Processor):
         with spans.span("jet.bridge.readback") if tracing else spans.OFF \
                 as sp:
             valid = np.asarray(out["valid"])
-            emitting = bool(valid.any())
+            rows = np.nonzero(valid)[0].tolist()
             nbytes = valid.nbytes
-            if emitting:
+            if rows:
                 if top1:
                     top = np.asarray(out["top"])
                     ends = top[0]
                     nbytes += top.nbytes
                 else:
-                    ends = np.asarray(out["window_ends"])
-                    res = np.asarray(out["results"])
+                    # through the last valid row, not the count of valid
+                    # rows: the slice holds every row even off a prefix
+                    res, ends = _leading_rows(out, _row_bucket(
+                        rows[-1] + 1, len(valid)))
                     nbytes += ends.nbytes + res.nbytes
             if tracing:
                 sp.set_metadata(bytes=nbytes)
         self._readback_bytes += nbytes
-        if not emitting:
+        if not rows:
             return
         self._emitting_steps += 1
-        rows = np.nonzero(valid)[0].tolist()
         bmap, sentinel = self._bucket_key, self._bkey_sentinel
         buf = self._emit_buf
         n0 = len(buf)
@@ -634,7 +675,10 @@ class DeviceWindowProcessor(Processor):
         return self._steps
 
     def telemetry(self) -> Dict[str, int]:
-        """``readback_bytes``: bytes of step output copied to the host;
+        """``readback_bytes``: bytes of step output copied to the host
+        (every step's ``valid`` mask; per emitting step, the top-1 form's
+        whole ``top`` array or the per-key form's emitted prefix of
+        ``window_ends`` and ``results``, rounded up to a power of two);
         ``emitting_steps``: harvested steps whose output held a window;
         ``reduced_windows``: window ends the device reduced to one partial
         (top-1 form; 0 in the per-key form); ``key_results``: per-key

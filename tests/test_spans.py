@@ -12,6 +12,7 @@ import gc
 import pathlib
 
 import jax
+import numpy as np
 import pytest
 
 from repro.core import (CollectorSink, DeviceWindowProcessor, JetCluster,
@@ -176,20 +177,32 @@ def test_flush_spans_carry_every_per_key_result(tmp_path, spans_off,
     assert 0 in calls
 
 
-def test_readback_counters_count_each_harvested_step(spans_off):
+def test_readback_counters_count_each_harvested_step(spans_off,
+                                                     monkeypatch):
+    real = DeviceWindowProcessor._convert
+    sliced = []
+
+    def convert(self, out):
+        valid = np.asarray(out["valid"])
+        if valid.any():
+            # the bucket that covers the last valid row
+            last = int(np.nonzero(valid)[0][-1])
+            sliced.append(min(1 << last.bit_length(), len(valid)))
+        return real(self, out)
+    monkeypatch.setattr(DeviceWindowProcessor, "_convert", convert)
     job, out = _q5_job(with_global_max=False)
     dev = [t.processor for t in job.execution.tasklets
            if isinstance(t.processor, DeviceWindowProcessor)]
     assert len(dev) == 1
     p = dev[0]
     rows = p._spec.emit_buffer_rows
-    per_emit = rows * 4 + rows * BUCKETS * 4
     tel = p.telemetry()
-    assert 0 < tel["emitting_steps"] <= p.steps
-    # every step's validity mask comes back; emitting steps add the
-    # window ends and the result matrix
+    assert 0 < tel["emitting_steps"] == len(sliced) <= p.steps
+    # every step's validity mask comes back; an emitting step adds the
+    # window ends and result rows of its emitted prefix, rounded up
     assert tel["readback_bytes"] == \
-        p.steps * rows + tel["emitting_steps"] * per_emit
+        p.steps * rows + sum(s * 4 + s * BUCKETS * 4 for s in sliced)
+    assert min(sliced) < rows
     stats = job.execution.stats()
     assert stats["readback_bytes"] == tel["readback_bytes"]
     assert stats["emitting_steps"] == tel["emitting_steps"]
