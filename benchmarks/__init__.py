@@ -1,1 +1,1 @@
-"""Benchmarks: one per paper table/figure + device tier."""
+"""The seeded chaos harness (``bench_chaos``); the benchmark is ``bench/run.py``."""

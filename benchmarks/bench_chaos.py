@@ -35,11 +35,9 @@ Scenarios:
   accounting) and complete within the restart budget with output equal
   to a run that never saw the record.
 
-Results land under a ``chaos`` key in ``BENCH_latency.json`` and as a
-compact record appended to ``BENCH_trajectory.json``; ``--smoke`` (the
-CI gate) can additionally dump the recovery diagnostics of its
-corruption + poison passes via ``--diagnostics PATH`` for the CI
-artifact.
+The results print as one JSON document on stdout; ``--smoke`` (the CI
+gate) can additionally dump the recovery diagnostics of its corruption +
+poison passes via ``--diagnostics PATH`` for the CI artifact.
 """
 
 from __future__ import annotations
@@ -500,70 +498,6 @@ def smoke(seed: int = 1) -> Dict:
     }
 
 
-def update_reports(section: Dict,
-                   root: Optional[pathlib.Path] = None) -> List[pathlib.Path]:
-    """Attach the chaos section to ``BENCH_latency.json`` and append a
-    compact record to ``BENCH_trajectory.json``."""
-    import subprocess
-    if root is None:
-        root = pathlib.Path(__file__).resolve().parents[1]
-    paths = []
-    latency = root / "BENCH_latency.json"
-    try:
-        report = json.loads(latency.read_text())
-    except (FileNotFoundError, ValueError):
-        report = {}
-    report["chaos"] = section
-    latency.write_text(json.dumps(report, indent=1, default=float) + "\n")
-    paths.append(latency)
-
-    try:
-        sha = subprocess.run(
-            ["git", "rev-parse", "--short", "HEAD"], cwd=root,
-            capture_output=True, text=True, timeout=10).stdout.strip() \
-            or "unknown"
-    except Exception:
-        sha = "unknown"
-    mp_runs = section.get("mp", [])
-    gaps = [f["recovery_gap_ms"] for r in mp_runs for f in r["faults"]
-            if f.get("recovery_gap_ms") is not None]
-    record = {
-        "sha": sha,
-        "utc": time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime()),
-        "chaos_seeds": section["meta"]["seeds"],
-        "chaos_fault_kinds_fired": sorted({k for r in mp_runs
-                                           for k in r["fault_kinds_fired"]}),
-        "chaos_verified": all(r["verification"]["results_match"]
-                              for r in mp_runs),
-        "chaos_max_recovery_gap_ms": max(gaps) if gaps else None,
-        "chaos_rescale_gap_ms":
-            section["rescale"]["rescale_recovery_gap_ms"],
-        "chaos_active_active_gap_ms":
-            section["active_active"]["flip_recovery_gap_ms"],
-        "corruption_verified":
-            (section["corruption"]["verification"]["results_match"]
-             and section["corruption"]["fallback"]
-                 ["all_corrupted_rejected"]),
-        "corruption_snapshots": section["corruption"]
-            ["corrupted_snapshots"],
-        "poison_quarantined":
-            (section["poison"]["completed"]
-             and section["poison"]["quarantined"] == 1
-             and section["poison"]["verification"]["results_match"]),
-    }
-    trajectory = root / "BENCH_trajectory.json"
-    try:
-        records = json.loads(trajectory.read_text())
-        if not isinstance(records, list):
-            records = []
-    except (FileNotFoundError, ValueError):
-        records = []
-    records.append(record)
-    trajectory.write_text(json.dumps(records, indent=1, default=float) + "\n")
-    paths.append(trajectory)
-    return paths
-
-
 if __name__ == "__main__":
     import argparse
     ap = argparse.ArgumentParser()
@@ -573,7 +507,7 @@ if __name__ == "__main__":
     ap.add_argument("--smoke", action="store_true",
                     help="CI gate: 1 kill + 1 delayed ack on 2 mp "
                          "workers, plus a snapshot-corruption and a "
-                         "poison-record pass; no report writes, nonzero "
+                         "poison-record pass; nonzero "
                          "exit on verification failure")
     ap.add_argument("--diagnostics", type=pathlib.Path, default=None,
                     help="with --smoke: dump the corruption + poison "
@@ -595,6 +529,4 @@ if __name__ == "__main__":
         sys.exit(0 if result["ok"] else 1)
     seeds = tuple(args.seeds) if args.seeds else (1, 2, 3)
     section = run(quick=not args.full, seeds=seeds)
-    for p in update_reports(section):
-        print(f"# updated {p}")
     print(json.dumps(section, indent=1, default=float))

@@ -227,8 +227,7 @@ def run_spmd(devices, *, seed: int = 0, n_steps: int = 24,
         ends, buckets, values = [], [], []
         snap_checked = False
         for i, b in enumerate(batches + [idle] * 16):
-            staged, cnt = ex.stage_batch(b)
-            state, out = ex.step(state, staged, valid_count=cnt)
+            state, out = ex.step(state, ex.stage_batch(b))
             valid = np.asarray(out["valid"])
             res = np.asarray(out["results"])
             for r in np.nonzero(valid)[0].tolist():

@@ -219,14 +219,13 @@ class DeviceWindowProcessor(Processor):
         # program) so the one-time compile cost lands at job start, not in
         # the middle of a paced run's latency measurement
         self._ensure_executor()
-        staged, cnt = self.executor.stage_batch({
+        staged = self.executor.stage_batch({
             "ts": np.zeros(self.batch_size, np.int32),
             "key": np.zeros(self.batch_size, np.int32),
             "value": np.zeros(self.batch_size, np.float32),
             "valid": np.zeros(self.batch_size, bool),
             "wm": np.asarray(-1, np.int32)})
-        self.state, out = self.executor.step(self.state, staged,
-                                             valid_count=cnt)
+        self.state, out = self.executor.step(self.state, staged)
         np.asarray(out["valid"])        # block: compilation finished
         if self.emission == "per_key":
             # and every slice the per-key readback may ask for
@@ -358,9 +357,8 @@ class DeviceWindowProcessor(Processor):
                          "key": np.pad(self._key[:n], pad),
                          "value": np.pad(self._val[:n], pad),
                          "valid": np.pad(np.ones(n, bool), pad), "wm": wm}
-            staged, cnt = self.executor.stage_batch(batch)
-            self.state, out = self.executor.step(self.state, staged,
-                                                 valid_count=cnt)
+            staged = self.executor.stage_batch(batch)
+            self.state, out = self.executor.step(self.state, staged)
             self._pending.append((wm_hint, out))
             self._steps += 1
             self._n = 0

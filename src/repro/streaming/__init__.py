@@ -4,7 +4,7 @@ The whole dataflow graph compiles into ONE XLA program executed SPMD on
 every chip (the tasklet model's "whole DAG on every core"), state is
 sharded so partitioning-of-state == partitioning-of-compute, keyed
 exchange is a reduce-scatter/all-to-all, and snapshots are consistent by
-construction at step boundaries (see DESIGN.md §2).
+construction at step boundaries.
 """
 
 from .window import VectorWindowSpec, window_state_init

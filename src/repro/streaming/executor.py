@@ -4,7 +4,7 @@ One compiled ``step``: ingest an event batch -> (optional) keyed exchange
 across the ``data`` mesh axis -> stage-1 pane accumulation -> stage-2
 reduce-scatter combine -> window emission, plus a ``snapshot`` collective
 that ring-replicates the sharded state to the next chip (the IMDG backup
-replica, DESIGN.md §2).
+replica).
 
 Key design points mirroring the paper:
 
@@ -13,20 +13,17 @@ Key design points mirroring the paper:
   lives on data-shard ``k % n_shards``, and the stage-2 combine is a
   ``psum_scatter`` over ``data`` that deposits exactly the buckets each
   chip owns (two-stage aggregation as a single collective);
-* credit-based backpressure — the host ingestion loop sizes each step's
-  admission to ~3x the measured per-interval processing rate (the
-  adaptive receive window, §3.3);
 * snapshots are consistent cuts by construction (step boundary), stored
-  as a ring-shifted replica on the neighbouring chip + an optional host
-  copy in the IMap service.
+  as a ring-shifted replica on the neighbouring chip.
+
+The executor admits whatever batch it is given; the adaptive receive
+window that paces senders (§3.3) is ``core/backpressure.NetworkLink``.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import functools
-import time
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -37,15 +34,11 @@ from ..compat import shard_map
 from .window import (EMIT_PER_KEY, VectorWindowSpec, accumulate, emit,
                      window_state_init)
 
-ACK_INTERVAL_S = 0.1
-WINDOW_FILL_FACTOR = 3
-
 
 @dataclasses.dataclass(frozen=True)
 class StreamJobConfig:
     window: VectorWindowSpec
     batch_size: int = 4096          # events per step (global)
-    snapshot_every: int = 0         # steps between snapshots (0 = off)
     #: keyed-exchange plan (SPMD only):
     #:  - "reduce": stage-1 accumulates FULL-width panes locally, one
     #:    psum_scatter combines+deposits (bytes ~ R*K/chip — wins when the
@@ -71,10 +64,6 @@ class StreamExecutor:
         self._step = jax.jit(self._build_step(), donate_argnums=(0,))
         self._snapshot = jax.jit(self._build_snapshot(), donate_argnums=())
         self._restore = jax.jit(self._build_restore())
-        # telemetry for the adaptive receive window
-        self._processed_since_ack = 0
-        self._last_ack = time.monotonic()
-        self._receive_window = cfg.batch_size * WINDOW_FILL_FACTOR
         # per-field shardings for async host->device staging
         if mesh is None:
             self._batch_shardings = None
@@ -180,9 +169,8 @@ class StreamExecutor:
     def _build_step_route(self, mesh, K_loc: int):
         """Route-then-accumulate exchange: events all-to-all to their
         bucket owners; panes are owner-local (R, K/n) — the exchange moves
-        O(events) bytes instead of O(R*K) (DESIGN.md: Jet's exchange
-        operator; the counting-sort positions are kernels/route.py's job
-        on real TPU)."""
+        O(events) bytes instead of O(R*K) (Jet's exchange operator; the
+        counting-sort positions are kernels/route.py's job on real TPU)."""
         spec = self.cfg.window
         n = self.n_shards
         B_loc = self.cfg.batch_size // n
@@ -299,27 +287,16 @@ class StreamExecutor:
         return target._shard_state(host)
 
     # ------------------------------------------------------------- run --
-    def step(self, state, batch, valid_count: Optional[int] = None):
-        """One compiled step.  Pass ``valid_count`` (host-side event count,
-        known at staging time) to keep the call fully asynchronous — without
-        it the admission telemetry forces a device sync per step."""
-        out = self._step(state, batch)
-        if valid_count is None:
-            valid = batch["valid"]
-            valid_count = int(valid.sum() if isinstance(valid, np.ndarray)
-                              else jnp.sum(valid))
-        self._processed_since_ack += valid_count
-        return out
+    def step(self, state, batch):
+        """One compiled step; returns ``(state, out)`` as device futures."""
+        return self._step(state, batch)
 
-    def stage_batch(self, batch: Dict) -> Tuple[Dict, int]:
+    def stage_batch(self, batch: Dict) -> Dict:
         """Begin the host->device transfer of ``batch`` without blocking.
 
         The copy overlaps whatever step is currently executing (async
         dispatch), which is what pipelines ingestion against compute.
-        Returns ``(device_batch, valid_count)`` — the count is taken on the
-        host *before* the transfer so the hot loop never syncs.
         """
-        count = int(np.asarray(batch["valid"]).sum())
         shardings = self._batch_shardings
         staged = {}
         for k, v in batch.items():
@@ -329,69 +306,10 @@ class StreamExecutor:
                 staged[k] = jax.device_put(np.asarray(v), shardings[k])
             else:
                 staged[k] = jax.device_put(np.asarray(v))
-        return staged, count
+        return staged
 
     def snapshot(self, state):
         return self._snapshot(state)
 
     def restore(self, backup):
         return self._restore(backup)
-
-    # adaptive receive window (paper §3.3): how many events the source may
-    # admit before the next ack
-    def admissible(self) -> int:
-        now = time.monotonic()
-        if now - self._last_ack >= ACK_INTERVAL_S:
-            rate = self._processed_since_ack
-            if rate > 0:
-                target = rate * WINDOW_FILL_FACTOR
-                self._receive_window = max(
-                    self.cfg.batch_size,
-                    (self._receive_window + target) // 2)
-            self._processed_since_ack = 0
-            self._last_ack = now
-        return self._receive_window
-
-    # ------------------------------------------------------------ bench --
-    #: device-held step outputs are converted to host arrays in chunks of
-    #: this many steps, bounding live buffers without a per-step sync
-    COLLECT_CHUNK = 64
-
-    def run_stream(self, event_gen: Callable[[int, int], Dict],
-                   n_steps: int, collect: bool = True):
-        """Drive ``n_steps`` steps; returns (state, results list).
-
-        The loop is pipelined: batch ``i+1`` is staged host->device while
-        step ``i`` executes, and step outputs stay on device (futures)
-        until a chunk boundary — no per-step host synchronization.
-        """
-        state = self.init_state()
-        results = []
-        pending_outs = []
-
-        def _harvest():
-            for out in pending_outs:
-                valid = np.asarray(out["valid"])
-                if valid.any():
-                    results.append(
-                        (np.asarray(out["window_ends"])[valid],
-                         np.asarray(out["results"])[valid.nonzero()[0]]))
-            pending_outs.clear()
-
-        B = self.cfg.batch_size
-        snap_every = self.cfg.snapshot_every
-        nxt, nxt_count = self.stage_batch(event_gen(0, B))
-        for i in range(n_steps):
-            batch, count = nxt, nxt_count
-            if i + 1 < n_steps:
-                # pipelining: next batch's transfer overlaps this step
-                nxt, nxt_count = self.stage_batch(event_gen((i + 1) * B, B))
-            state, out = self.step(state, batch, valid_count=count)
-            if snap_every and (i + 1) % snap_every == 0:
-                self._last_backup = self.snapshot(state)
-            if collect:
-                pending_outs.append(out)
-                if len(pending_outs) >= self.COLLECT_CHUNK:
-                    _harvest()
-        _harvest()
-        return state, results

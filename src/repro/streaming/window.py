@@ -7,8 +7,8 @@ pane-based plan as the host tier (core/window.py), vectorized:
 
 * **accumulate** (Jet stage 1): the batch scatters into the (K, R) pane
   matrix (``segment-sum`` here; the MXU-tiled one-hot-matmul version is
-  the Pallas kernel in ``kernels/window_agg`` — DESIGN.md "scatter-add ->
-  one-hot matmul"),
+  the Pallas kernel in ``kernels/window_agg``: scatter-add as a one-hot
+  matmul),
 * **combine + emit** (Jet stage 2): when the watermark crosses a slide
   boundary, the window result per key is ``panes_ring @ window_mask`` —
   one matvec per emitted window.  ``VectorWindowSpec.emission`` picks
@@ -31,7 +31,7 @@ cannot leave emission permanently behind.  Events that arrive after their
 last window emitted are dropped and counted (``dropped_late``), events
 whose ring slot is still occupied by a live older frame are dropped and
 counted (``dropped_conflict`` — bounded by pacing ingestion against
-emission, which is the executor's credit-based backpressure job).
+emission, which is the job of the backpressure in front of the device).
 
 ``wm_lag`` is the bounded-out-of-orderness allowance (the host tier's
 ``EventTimePolicy.lag``): the data-driven watermark frontier is
