@@ -11,17 +11,22 @@ the device tier's fused accumulate+emit step (:mod:`repro.streaming`):
 * **Packing** — incoming :class:`~repro.core.events.EventBlock` columns
   (the host hot path) append into fixed-size staging arrays; scalar
   :class:`~repro.core.events.Event`\\ s take the same arrays one row at a
-  time.  A full staging buffer becomes one padded device batch
-  ``{ts, key, value, valid, wm}``: the tail rows carry ``valid=False``
-  and keys hash-bucket into ``n_key_buckets`` via ``key % n_key_buckets``
-  (injective whenever the key space fits the bucket count; wider key
-  spaces aggregate per *bucket* — the documented caveat).  The original
-  key of every bucket is remembered host-side so emissions convert back.
-* **Async drive** — batch *i+1* stages (``stage_batch``) while step *i*
-  executes; step outputs stay on device as futures in an ordered pending
-  list and are only materialized once ``is_ready()`` (polled from
-  ``poll_async`` / the watermark path), so the cooperative tasklet loop
-  NEVER blocks on the device.
+  time.  A full staging buffer (or a partial one, at a watermark) becomes
+  one device batch ``{ts, key, value, valid, wm}``, handed to
+  ``stage_batch`` as views of the staging arrays: the executor packs
+  them into one int32 array, the tail rows of a partial batch padded
+  with ``valid=False``, and stages it in ONE host→device transfer
+  (``host_transfers`` counts them; the step unpacks the columns on the
+  device).  Keys hash-bucket into ``n_key_buckets`` via ``key %
+  n_key_buckets`` (injective whenever the key space fits the bucket
+  count; wider key spaces aggregate per *bucket* — the documented
+  caveat).  The original key of every bucket is remembered host-side so
+  emissions convert back.
+* **Async drive** — batch *i+1* stages (``stage_batch``: one packed
+  transfer) while step *i* executes; step outputs stay on device as
+  futures in an ordered pending list and are only materialized once
+  ``is_ready()`` (polled from ``poll_async`` / the watermark path), so
+  the cooperative tasklet loop NEVER blocks on the device.
 * **Watermarks** — the device runs in hint-only frontier mode
   (``frontier_from_data=False``): host watermarks (already lagged at the
   source) are the only event-time authority, so every device instance
@@ -147,13 +152,14 @@ class DeviceWindowProcessor(Processor):
     #: finish_snapshot_restore).  _emit_buf flushes before the barrier,
     #: watermark cursors re-advance from replayed sources, executor/_spec
     #: are rebuilt lazily by _ensure_executor, and _bucket_collisions,
-    #: _readback_bytes, _emitting_steps, _reduced_windows and
-    #: _key_results are telemetry.
+    #: _readback_bytes, _emitting_steps, _reduced_windows, _key_results
+    #: and _host_transfers are telemetry.
     EPHEMERAL_STATE = frozenset({
         "_ts", "_key", "_val", "_n", "_pending", "_emit_buf", "_steps",
         "_progress_hint", "_last_wm", "_wm_submitted", "_closed",
         "_spec", "_bucket_collisions", "_readback_bytes",
         "_emitting_steps", "_reduced_windows", "_key_results",
+        "_host_transfers",
     })
 
     def __init__(self, wdef: SlidingWindowDef, op: AggregateOperation,
@@ -187,6 +193,7 @@ class DeviceWindowProcessor(Processor):
         self._ts = np.zeros(B, np.int32)
         self._key = np.zeros(B, np.int32)
         self._val = np.zeros(B, np.float32)
+        self._all_valid = np.ones(B, bool)
         self._n = 0
         #: bucket -> original key (the inverse of the packing hash; first
         #: writer wins on collision — see the module docstring caveat).
@@ -207,6 +214,7 @@ class DeviceWindowProcessor(Processor):
         self._emitting_steps = 0         # telemetry: steps that emitted
         self._reduced_windows = 0        # telemetry: top-1 partials sent
         self._key_results = 0            # telemetry: per-key results sent
+        self._host_transfers = 0         # telemetry: host->device transfers
         self._progress_hint = False      # last _harvest_ready made progress
         self._snap_entries: Optional[List[Tuple[Any, Any]]] = None
         self._restore_frames: Dict[Any, Dict[int, float]] = {}
@@ -329,11 +337,10 @@ class DeviceWindowProcessor(Processor):
     # ------------------------------------------------------- device drive --
     def _submit(self, wm_hint: Optional[int] = None,
                 window_end: Optional[int] = None) -> None:
-        """Stage the current staging buffer as one padded device batch and
-        dispatch the step asynchronously; the output joins the pending
-        list as a device future.  ``window_end``: the highest window end
-        the hint closes, given on the first step that carries it (a span
-        attribute only)."""
+        """Stage the staged rows as one device batch and dispatch the step
+        asynchronously; the output joins the pending list as a device
+        future.  ``window_end``: the highest window end the hint closes,
+        given on the first step that carries it (a span attribute only)."""
         tracing = spans.on
         if tracing:
             attrs = {"rows": self._n, "wm": -1 if wm_hint is None
@@ -341,24 +348,21 @@ class DeviceWindowProcessor(Processor):
             if window_end is not None:
                 attrs["window_end"] = window_end
         with spans.span("jet.bridge.submit", **attrs) if tracing \
-                else spans.OFF:
+                else spans.OFF as sp:
             n = self._n
-            B = self.batch_size
-            wm = np.asarray(-1 if wm_hint is None else wm_hint, np.int32)
-            if n == B:
-                batch = {"ts": self._ts.copy(), "key": self._key.copy(),
-                         "value": self._val.copy(),
-                         "valid": np.ones(B, bool), "wm": wm}
-            else:
-                # pad the partial burst to the fixed device batch size
-                # (np.pad copies, so the staging buffers stay reusable)
-                pad = (0, B - n)
-                batch = {"ts": np.pad(self._ts[:n], pad),
-                         "key": np.pad(self._key[:n], pad),
-                         "value": np.pad(self._val[:n], pad),
-                         "valid": np.pad(np.ones(n, bool), pad), "wm": wm}
-            staged = self.executor.stage_batch(batch)
-            self.state, out = self.executor.step(self.state, staged)
+            ex = self.executor
+            sent = ex.host_transfers
+            # views: stage_batch's pack is the one copy (and pads a
+            # partial batch), so the staging buffers stay reusable
+            staged = ex.stage_batch({
+                "ts": self._ts[:n], "key": self._key[:n],
+                "value": self._val[:n], "valid": self._all_valid[:n],
+                "wm": np.int32(-1 if wm_hint is None else wm_hint)})
+            self.state, out = ex.step(self.state, staged)
+            sent = ex.host_transfers - sent
+            self._host_transfers += sent
+            if tracing:
+                sp.set_metadata(transfers=sent)
             self._pending.append((wm_hint, out))
             self._steps += 1
             self._n = 0
@@ -680,8 +684,11 @@ class DeviceWindowProcessor(Processor):
         ``emitting_steps``: harvested steps whose output held a window;
         ``reduced_windows``: window ends the device reduced to one partial
         (top-1 form; 0 in the per-key form); ``key_results``: per-key
-        ``WindowResult`` events sent (per-key form; 0 in the top-1 form)."""
+        ``WindowResult`` events sent (per-key form; 0 in the top-1 form);
+        ``host_transfers``: host->device transfers the steps' staging
+        issued (one a step)."""
         return {"readback_bytes": self._readback_bytes,
                 "emitting_steps": self._emitting_steps,
                 "reduced_windows": self._reduced_windows,
-                "key_results": self._key_results}
+                "key_results": self._key_results,
+                "host_transfers": self._host_transfers}

@@ -355,3 +355,104 @@ def test_device_placement_rejects_host_only_features():
     from repro.core.window import SlidingWindowDef
     with pytest.raises(ValueError):
         DeviceWindowProcessor(SlidingWindowDef(100, 10), to_list())
+
+
+# ---------------------------------------------------------------------------
+# One packed host→device transfer a step
+# ---------------------------------------------------------------------------
+
+
+def _bits(tree):
+    """Every leaf as raw bytes of its own dtype: equal means bit for bit."""
+    import jax
+    return jax.tree.map(lambda x: np.asarray(x).tobytes(), tree)
+
+
+@pytest.mark.parametrize("emission", ["per_key", "top1"])
+@pytest.mark.parametrize("rows", [64, 41])
+@pytest.mark.parametrize("wm", [-1, 150, None])
+@pytest.mark.parametrize("values", ["ones", "fractions"])
+def test_packed_step_equals_the_column_step(emission, rows, wm, values):
+    """``step`` on the packed array ``stage_batch`` makes returns the same
+    state and outputs as ``step`` on the padded column dict, bit for bit:
+    a full and a partial batch, a watermark of -1, a hint and none, counts
+    and non-integral f32 sums."""
+    import jax
+    B = 64
+    spec = VectorWindowSpec(size_ms=40, slide_ms=10, n_key_buckets=32,
+                            max_windows_per_step=4, ring_margin=8,
+                            frontier_from_data=False, emission=emission)
+    ex = StreamExecutor(StreamJobConfig(window=spec, batch_size=B))
+    rng = np.random.RandomState(rows)
+
+    def columns(lo, n):
+        val = (np.ones(n, np.float32) if values == "ones"
+               else rng.rand(n).astype(np.float32) * 7.3)
+        return {"ts": np.sort(rng.randint(lo, lo + 100, n)).astype(np.int32),
+                "key": rng.randint(0, 32, n).astype(np.int32),
+                "value": val, "valid": np.ones(n, bool),
+                "wm": None if wm is None else np.int32(wm)}
+
+    # a state with live frames, then the step under test
+    st, _ = ex.step(ex.init_state(), ex.stage_batch(columns(0, B)))
+    batch = columns(100, rows)
+    pad = (0, B - rows)
+    padded = {k: v if k == "wm" else jnp.asarray(np.pad(v, pad))
+              for k, v in batch.items()}
+    copy = lambda s: jax.tree.map(jnp.copy, s)    # noqa: E731 (donated)
+    sent = ex.host_transfers
+    staged = ex.stage_batch(batch)
+    assert ex.host_transfers - sent == 1
+    assert staged.shape == (4 * B + 1,) and staged.dtype == jnp.int32
+    want = ex.step(copy(st), padded)
+    got = ex.step(copy(st), staged)
+    assert _bits(got) == _bits(want)
+    if wm is not None and wm >= 0:
+        assert np.asarray(want[1]["valid"]).any()   # the hint emitted
+
+
+def _q5_device_job(threads=2):
+    gen = NexmarkGenerator(rate=60_000, n_keys=40)
+    cluster = JetCluster(n_nodes=1, cooperative_threads=threads,
+                         clock=VirtualClock(auto_step=0.001))
+    out = []
+    p = queries.q5(
+        lambda: PacedGeneratorSource(gen, rate=60_000, max_events=12_000),
+        lambda: CollectorSink(out), window_ms=100, slide_ms=20,
+        placement="device", device=dict(n_key_buckets=64, batch_size=256))
+    job = cluster.submit(p.to_dag(), JobConfig())
+    for _ in range(4_000_000):
+        if job.status == JOB_COMPLETED:
+            break
+        cluster.step()
+    assert job.status == JOB_COMPLETED
+    return job, {(ev.value.window_end, ev.key): ev.value.value for ev in out}
+
+
+def test_one_host_transfer_a_step():
+    from repro.core import DeviceWindowProcessor
+    job, got = _q5_device_job()
+    devs = [t.processor for t in job.execution.tasklets
+            if isinstance(t.processor, DeviceWindowProcessor)]
+    assert len(devs) == 2 and got
+    for p in devs:
+        assert p.telemetry()["host_transfers"] == p.steps > 0
+    assert job.execution.stats()["host_transfers"] == \
+        sum(p.steps for p in devs)
+
+
+def test_packed_batch_honours_the_callers_mask(monkeypatch):
+    """Rows whose ``valid`` the caller clears before ``stage_batch`` count
+    nowhere on the device: clearing every even bucket leaves exactly the
+    odd buckets' counts of the unaltered run."""
+    _job, full = _q5_device_job()
+    real = StreamExecutor.stage_batch
+
+    def stage(self, batch):
+        valid = np.array(batch["valid"])
+        valid &= np.asarray(batch["key"]) % 2 == 1
+        return real(self, dict(batch, valid=valid))
+    monkeypatch.setattr(StreamExecutor, "stage_batch", stage)
+    _job, odd = _q5_device_job()
+    assert any(k % 64 % 2 == 0 for _w, k in full)
+    assert odd == {wk: v for wk, v in full.items() if wk[1] % 64 % 2 == 1}

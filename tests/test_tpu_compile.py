@@ -107,6 +107,25 @@ def test_single_chip_top1_step_holds_no_result_matrix(one_chip):
     assert shapes["per_key"]["results"] == ((512, BUCKETS), jnp.float32)
 
 
+@pytest.mark.parametrize("emission", ["per_key", "top1"])
+def test_single_chip_packed_step_compiles(one_chip, emission):
+    """The bridge's form at the q5 cells' shape (batch 8,192): one packed
+    int32 argument of 4 * 8,192 + 1, unpacked inside the one compiled
+    program, which keeps the ``jit_step`` name the device trace reads."""
+    spec = VectorWindowSpec(size_ms=10_000, slide_ms=20,
+                            n_key_buckets=BUCKETS, max_windows_per_step=8,
+                            ring_margin=8, frontier_from_data=False,
+                            emission=emission)
+    ex = StreamExecutor(StreamJobConfig(window=spec, batch_size=8192))
+    state, _batch = _shapes(spec, lambda k: one_chip, lambda k: one_chip)
+    packed = jax.ShapeDtypeStruct((4 * 8192 + 1,), jnp.int32,
+                                  sharding=one_chip)
+    lowered = ex._step.lower(state, packed)
+    assert lowered.as_text().startswith("module @jit_step")
+    mem = lowered.compile().memory_analysis()
+    assert mem.argument_size_in_bytes < V5E_HBM
+
+
 @pytest.mark.parametrize("exchange,collective",
                          [("reduce", "reduce-scatter"),
                           ("route", "all-to-all")])
